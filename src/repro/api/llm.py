@@ -19,10 +19,16 @@ Example:
                    dtype="float32", cache_len=64)
     outs = llm.generate(prompts, SamplingParams(max_new=8))
 
-Note on devices: engine="shard" builds a (dp, tp) mesh, so the process
-must expose dp*tp devices BEFORE jax initializes (e.g.
-`XLA_FLAGS=--xla_force_host_platform_device_count=N`); engine="sim"
-simulates TP with vmap on a single device and requires dp == 1.
+Note on devices: engine="shard" builds a (dp, tp) mesh over the first
+dp*tp devices JAX exposes.  On a TPU host those are the chips, ordered
+along the interconnect (launch/mesh.py); the host-device-count flag
+(`XLA_FLAGS=--xla_force_host_platform_device_count=N`, set before jax
+initializes) only shapes the CPU backend, where it provides N virtual
+devices.  engine="sim" simulates TP with vmap on a single device and
+requires dp == 1.
+
+Canonical weights live on the host (the CPU device) and each placement
+is built there, so an accelerator only ever holds placed shards.
 """
 from __future__ import annotations
 
@@ -35,6 +41,12 @@ from repro.api.sampling import SamplingParams
 from repro.api.scheduler import CacheConfig, Request, Scheduler
 from repro.config.base import (CommPolicy, ModelConfig, SPDPlanConfig,
                                SYNC_LEVELS, replace)
+
+
+def _host():
+    """The CPU device that holds canonical weights and builds placements."""
+    import jax
+    return jax.local_devices(backend="cpu")[0]
 
 
 def _resolve_comm(comm, n_layers: int,
@@ -95,7 +107,7 @@ class LLM:
         self.engine_kind = engine_kind
         self.engine = engine
         self.params = params          # engine-placed (split or sharded)
-        self.canonical = canonical    # host canonical tree (for apply_spd)
+        self.canonical = canonical    # canonical tree on the host CPU device
         self.cache = cache
         self.mesh = mesh
         self.tp, self.dp, self.q_chunk = tp, dp, q_chunk
@@ -202,7 +214,9 @@ class LLM:
         from repro.cluster.router import make_policy
         make_policy(router)           # fail fast on unknown policy names
         canonical = (params if params is not None
-                     else M.init_model(jax.random.PRNGKey(seed), cfg))
+                     else jax.device_put(
+                         M.init_model(jax.random.PRNGKey(seed), cfg),
+                         _host()))
         cache = CacheConfig(cache_len=cache_len, max_batch=max_batch,
                             page_size=page_size, num_pages=num_pages,
                             prefill_chunk=prefill_chunk)
@@ -248,12 +262,14 @@ class LLM:
         steps can never disagree on segmentation; the draft engine
         places the SAME canonical tensors under its own plan — zero
         extra trained weights, just a second layout."""
+        import jax
         from repro.core import model as M
 
         backend = (engine if engine is not None else self.engine).backend
-        pt = tree if padded else M.pad_model(tree, self.cfg, self.tp)
-        return backend.place_params(
-            M.stack_segments(pt, self.cfg, backend.plan))
+        with jax.default_device(_host()):
+            pt = tree if padded else M.pad_model(tree, self.cfg, self.tp)
+            stacked = M.stack_segments(pt, self.cfg, backend.plan)
+        return backend.place_params(stacked)
 
     # ---------------- speculative decoding ----------------
 

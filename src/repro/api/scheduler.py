@@ -1115,7 +1115,9 @@ class Scheduler:
         self._admit()
         active = self._active()
         if not active:
-            return False
+            # every admitted request may have finished on its admission
+            # token; requests still queued need another step
+            return bool(self.queue)
         if self.spec is not None:
             return self._spec_step(active)
         if self.kv.paged:

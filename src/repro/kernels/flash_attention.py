@@ -72,7 +72,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 def _paged_flash_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
                         acc_ref, m_ref, l_ref, *, sm_scale: float,
                         n_pages: int, trash: int):
-    """One grid step per (slot, logical page).
+    """One grid step per (slot, query block, logical page).
 
     The page table and chunk-start positions arrive as scalar-prefetch
     refs: BlockSpec index maps read `pt_ref` to pick WHICH physical K/V
@@ -82,7 +82,8 @@ def _paged_flash_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
     grid dimension; heads ride as the leading batch of a 3-d dot_general
     so GQA needs no materialized head broadcast in HBM."""
     b = pl.program_id(0)
-    j = pl.program_id(1)
+    qi = pl.program_id(1)
+    j = pl.program_id(2)
 
     @pl.when(j == 0)
     def _init():
@@ -90,13 +91,13 @@ def _paged_flash_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0].astype(jnp.float32)               # (C, Hq, D)
+    q = q_ref[0].astype(jnp.float32)               # (bc, Hq, D)
     k = k_ref[0].astype(jnp.float32)               # (ps, Hkv, D)
     v = v_ref[0].astype(jnp.float32)
     c, hq, d = q.shape
     ps, hkv, _ = k.shape
     g = hq // hkv
-    # heads-as-batch: q (Hq, C, D) x k (Hq, ps, D) -> s (Hq, C, ps)
+    # heads-as-batch: q (Hq, bc, D) x k (Hq, ps, D) -> s (Hq, bc, ps)
     qt = q.transpose(1, 0, 2)
     kt = jnp.repeat(k.transpose(1, 0, 2), g, axis=0)
     vt = jnp.repeat(v.transpose(1, 0, 2), g, axis=0)
@@ -105,7 +106,8 @@ def _paged_flash_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
     # causal mask over absolute positions + trash mask for -1 entries
     # (the caller maps -1 -> trash before prefetch; `== trash` recovers
     # the sign since no real table entry can equal the trash index)
-    qpos = pos_ref[b] + jax.lax.broadcasted_iota(jnp.int32, (hq, c, ps), 1)
+    qpos = (pos_ref[b] + qi * c
+            + jax.lax.broadcasted_iota(jnp.int32, (hq, c, ps), 1))
     kvpos = j * ps + jax.lax.broadcasted_iota(jnp.int32, (hq, c, ps), 2)
     valid = (kvpos <= qpos) & (pt_ref[b * n_pages + j] != trash)
     s = jnp.where(valid, s, NEG_INF)
@@ -133,6 +135,21 @@ def _paged_flash_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
         o_ref[0] = o.astype(o_ref.dtype)
 
 
+#: query rows (heads x chunk positions) one paged-kernel grid step holds:
+#: its fp32 scratch and (Hq, bc, ps) intermediates stay within the TPU's
+#: 16 MiB scoped VMEM (a 256-token chunk at 16 heads does not)
+PAGED_Q_ROWS = 512
+
+
+def _query_block(c: int, hq: int) -> int:
+    """Largest divisor of the chunk length with hq * block <= the row
+    budget (1 when even one position per head exceeds it)."""
+    bc = max(1, min(c, PAGED_Q_ROWS // hq))
+    while c % bc:
+        bc -= 1
+    return bc
+
+
 def paged_flash_attention(q, k_pool, v_pool, page_table, pos, *,
                           sm_scale=None, interpret=False):
     """Paged-KV causal flash attention reading K/V through a page table.
@@ -143,9 +160,11 @@ def paged_flash_attention(q, k_pool, v_pool, page_table, pos, *,
     page_table (B, n) int32 maps logical page j of slot b to a physical
     page, -1 = unallocated (reads the trash page, fully masked).
 
-    The grid is (B, n) with pages minor-most (sequential on TPU); the
-    page table is scalar-prefetched so each K/V BlockSpec fetch DMAs the
-    one physical page it needs — no contiguous (B, n*ps) materialization
+    The grid is (B, C / bc, n) with pages minor-most (sequential on
+    TPU): long chunks split into query blocks of bc positions
+    (`_query_block`), each streaming the slot's pages again.  The page
+    table is scalar-prefetched so each K/V BlockSpec fetch DMAs the one
+    physical page it needs — no contiguous (B, n*ps) materialization
     ever exists.  Oracle: kernels/ref.paged_attention_ref."""
     b, c, hq, d = q.shape
     pn1, ps, hkv, _ = k_pool.shape
@@ -154,28 +173,24 @@ def paged_flash_attention(q, k_pool, v_pool, page_table, pos, *,
     sm_scale = float(sm_scale if sm_scale is not None else d ** -0.5)
     trash = pn1 - 1
     pt = jnp.where(page_table < 0, trash, page_table).astype(jnp.int32)
+    bc = _query_block(c, hq)
 
     kernel = functools.partial(_paged_flash_kernel, sm_scale=sm_scale,
                                n_pages=n, trash=trash)
+    q_spec = pl.BlockSpec((1, bc, hq, d),
+                          lambda b, qi, j, pt_ref, pos_ref: (b, qi, 0, 0))
+    kv_spec = pl.BlockSpec((1, ps, hkv, d),
+                           lambda b, qi, j, pt_ref, pos_ref:
+                           (pt_ref[b * n + j], 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, n),
-        in_specs=[
-            pl.BlockSpec((1, c, hq, d),
-                         lambda b, j, pt_ref, pos_ref: (b, 0, 0, 0)),
-            pl.BlockSpec((1, ps, hkv, d),
-                         lambda b, j, pt_ref, pos_ref:
-                         (pt_ref[b * n + j], 0, 0, 0)),
-            pl.BlockSpec((1, ps, hkv, d),
-                         lambda b, j, pt_ref, pos_ref:
-                         (pt_ref[b * n + j], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, c, hq, d),
-                               lambda b, j, pt_ref, pos_ref: (b, 0, 0, 0)),
+        grid=(b, c // bc, n),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((hq * c, d), jnp.float32),    # acc
-            pltpu.VMEM((hq * c, 1), jnp.float32),    # running max
-            pltpu.VMEM((hq * c, 1), jnp.float32),    # running denom
+            pltpu.VMEM((hq * bc, d), jnp.float32),   # acc
+            pltpu.VMEM((hq * bc, 1), jnp.float32),   # running max
+            pltpu.VMEM((hq * bc, 1), jnp.float32),   # running denom
         ])
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,

@@ -27,10 +27,23 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def _pad_rows(flat, chunk):
-    n = flat.size
-    pad = (-n) % chunk
-    return jnp.pad(flat, (0, pad)).reshape(-1, chunk), n
+def _tile(flat, chunk, block_rows):
+    """(N,) -> ((R, chunk) zero-padded rows, block height).  The block is
+    the whole array when it fits in `block_rows`, else `block_rows` high
+    with R padded to a multiple of it — so every block is either the
+    full array or a multiple of the int8 tile height (32 sublanes), the
+    two shapes the TPU compiler accepts.  Zero rows quantize to zero."""
+    assert block_rows % 32 == 0, block_rows
+    rows = -(-flat.size // chunk)
+    br = rows if rows <= block_rows else block_rows
+    rows = -(-rows // br) * br
+    return jnp.pad(flat, (0, rows * chunk - flat.size)).reshape(rows, chunk), br
+
+
+def _scale_col(scales, rows):
+    """(ceil(N/chunk),) scales -> the kernels' (R, 1) column."""
+    s = scales.astype(jnp.float32).reshape(-1)
+    return jnp.pad(s, (0, rows - s.size)).reshape(rows, 1)
 
 
 def _scales(x, levels):
@@ -49,23 +62,19 @@ def _quant_kernel(x_ref, q_ref, s_ref, *, levels: int):
     x = x_ref[...].astype(jnp.float32)
     s = _scales(x, levels)
     q_ref[...] = jnp.clip(jnp.round(x / s), -levels, levels).astype(jnp.int8)
-    s_ref[...] = s[:, 0]
+    s_ref[...] = s
 
 
 def _dequant_kernel(q_ref, s_ref, y_ref):
-    y_ref[...] = q_ref[...].astype(jnp.float32) * s_ref[...][:, None]
+    y_ref[...] = q_ref[...].astype(jnp.float32) * s_ref[...]
 
 
 def _dequant_accum_kernel(q_ref, s_ref, a_ref, y_ref):
-    y_ref[...] = a_ref[...] \
-        + q_ref[...].astype(jnp.float32) * s_ref[...][:, None]
+    y_ref[...] = a_ref[...] + q_ref[...].astype(jnp.float32) * s_ref[...]
 
 
-def _grid(rows, block_rows):
-    br = min(block_rows, rows)
-    while rows % br:
-        br -= 1
-    return rows // br, br
+def _rows_spec(br, width):
+    return pl.BlockSpec((br, width), lambda i: (i, 0))
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "levels", "block_rows",
@@ -74,18 +83,17 @@ def qdq_absmax(x, *, chunk: int = 128, levels: int = 127,
                block_rows: int = 256, interpret: bool = False):
     """x (N,) -> quantize-dequantize round trip (fp32), per-chunk absmax."""
     flat = x.astype(jnp.float32).reshape(-1)
-    rows2d, n = _pad_rows(flat, chunk)
-    g, br = _grid(rows2d.shape[0], block_rows)
+    x2d, br = _tile(flat, chunk, block_rows)
     y = pl.pallas_call(
         functools.partial(_qdq_kernel, levels=levels),
-        grid=(g,),
-        in_specs=[pl.BlockSpec((br, chunk), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((br, chunk), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct(rows2d.shape, jnp.float32),
+        grid=(x2d.shape[0] // br,),
+        in_specs=[_rows_spec(br, chunk)],
+        out_specs=_rows_spec(br, chunk),
+        out_shape=jax.ShapeDtypeStruct(x2d.shape, jnp.float32),
         interpret=interpret,
         name="qdq_absmax",
-    )(rows2d)
-    return y.reshape(-1)[:n]
+    )(x2d)
+    return y.reshape(-1)[:flat.size]
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "levels", "block_rows",
@@ -94,21 +102,19 @@ def quantize_absmax(x, *, chunk: int = 128, levels: int = 127,
                     block_rows: int = 256, interpret: bool = False):
     """x (N,) -> (codes int8 (N,), scales fp32 (ceil(N/chunk),))."""
     flat = x.astype(jnp.float32).reshape(-1)
-    rows2d, n = _pad_rows(flat, chunk)
-    rows = rows2d.shape[0]
-    g, br = _grid(rows, block_rows)
+    x2d, br = _tile(flat, chunk, block_rows)
+    rows = x2d.shape[0]
     q, s = pl.pallas_call(
         functools.partial(_quant_kernel, levels=levels),
-        grid=(g,),
-        in_specs=[pl.BlockSpec((br, chunk), lambda i: (i, 0))],
-        out_specs=[pl.BlockSpec((br, chunk), lambda i: (i, 0)),
-                   pl.BlockSpec((br,), lambda i: (i,))],
+        grid=(rows // br,),
+        in_specs=[_rows_spec(br, chunk)],
+        out_specs=[_rows_spec(br, chunk), _rows_spec(br, 1)],
         out_shape=[jax.ShapeDtypeStruct((rows, chunk), jnp.int8),
-                   jax.ShapeDtypeStruct((rows,), jnp.float32)],
+                   jax.ShapeDtypeStruct((rows, 1), jnp.float32)],
         interpret=interpret,
         name="quantize_absmax",
-    )(rows2d)
-    return q.reshape(-1)[:n], s
+    )(x2d)
+    return q.reshape(-1)[:flat.size], s[:-(-flat.size // chunk), 0]
 
 
 @functools.partial(jax.jit, static_argnames=("n", "chunk", "block_rows",
@@ -116,19 +122,17 @@ def quantize_absmax(x, *, chunk: int = 128, levels: int = 127,
 def dequantize_absmax(q, scales, *, n: int, chunk: int = 128,
                       block_rows: int = 256, interpret: bool = False):
     """(codes int8 (N,), scales (ceil(N/chunk),)) -> fp32 (n,)."""
-    rows2d, _ = _pad_rows(q.astype(jnp.float32).reshape(-1), chunk)
-    rows = rows2d.shape[0]
-    g, br = _grid(rows, block_rows)
+    q2d, br = _tile(q.astype(jnp.int8).reshape(-1), chunk, block_rows)
+    rows = q2d.shape[0]
     y = pl.pallas_call(
         _dequant_kernel,
-        grid=(g,),
-        in_specs=[pl.BlockSpec((br, chunk), lambda i: (i, 0)),
-                  pl.BlockSpec((br,), lambda i: (i,))],
-        out_specs=pl.BlockSpec((br, chunk), lambda i: (i, 0)),
+        grid=(rows // br,),
+        in_specs=[_rows_spec(br, chunk), _rows_spec(br, 1)],
+        out_specs=_rows_spec(br, chunk),
         out_shape=jax.ShapeDtypeStruct((rows, chunk), jnp.float32),
         interpret=interpret,
         name="dequantize_absmax",
-    )(rows2d.astype(jnp.float32), scales)
+    )(q2d, _scale_col(scales, rows))
     return y.reshape(-1)[:n]
 
 
@@ -142,20 +146,17 @@ def dequant_accum_absmax(q, scales, acc, *, chunk: int = 128,
     is widened, rescaled, and folded into the local partial without a
     separate dequantized intermediate hitting HBM."""
     flat = acc.astype(jnp.float32).reshape(-1)
-    n = flat.size
-    rows2d, _ = _pad_rows(q.astype(jnp.float32).reshape(-1), chunk)
-    acc2d, _ = _pad_rows(flat, chunk)
-    rows = rows2d.shape[0]
-    g, br = _grid(rows, block_rows)
+    q2d, br = _tile(q.astype(jnp.int8).reshape(-1), chunk, block_rows)
+    acc2d, _ = _tile(flat, chunk, block_rows)
+    rows = q2d.shape[0]
     y = pl.pallas_call(
         _dequant_accum_kernel,
-        grid=(g,),
-        in_specs=[pl.BlockSpec((br, chunk), lambda i: (i, 0)),
-                  pl.BlockSpec((br,), lambda i: (i,)),
-                  pl.BlockSpec((br, chunk), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((br, chunk), lambda i: (i, 0)),
+        grid=(rows // br,),
+        in_specs=[_rows_spec(br, chunk), _rows_spec(br, 1),
+                  _rows_spec(br, chunk)],
+        out_specs=_rows_spec(br, chunk),
         out_shape=jax.ShapeDtypeStruct((rows, chunk), jnp.float32),
         interpret=interpret,
         name="dequant_accum_absmax",
-    )(rows2d, scales, acc2d)
-    return y.reshape(-1)[:n]
+    )(q2d, _scale_col(scales, rows), acc2d)
+    return y.reshape(-1)[:flat.size]

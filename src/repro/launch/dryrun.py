@@ -22,7 +22,6 @@ Usage:
 """
 import argparse
 import json
-import re
 import sys
 
 
@@ -102,14 +101,12 @@ def param_structs(cfg, plan, tp):
 
 
 def _collective_hlo_counts(txt: str):
-    """Count collective CALL SITES in compiled HLO ('... = shape op(...)');
-    note ops inside while bodies execute once per trip — the ledger is the
-    byte-exact accounting, this is the structural cross-check."""
-    out = {}
-    for op in ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
-               "collective-permute"):
-        out[op] = len(re.findall(rf"\b{op}(?:-start)?\(", txt))
-    return out
+    """Collective CALL SITES in compiled HLO; ops inside while bodies
+    execute once per trip — the ledger is the byte-exact accounting,
+    this is the structural cross-check (parallel/hlo.py also counts
+    executions)."""
+    from repro.parallel.hlo import collective_counts
+    return {op: c["sites"] for op, c in collective_counts(txt).items()}
 
 
 def bytes_per_device(total, mesh_axes_in_spec):

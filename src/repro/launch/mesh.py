@@ -30,7 +30,13 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_test_mesh(dp: int, tp: int, pod: int = 0):
+    """(dp, tp) "data" x "model" mesh (or (pod, dp, tp)) over the first
+    devices JAX exposes.  On a TPU host the devices are the chips, laid
+    out by `mesh_utils.create_device_mesh` so the minor "model" axis
+    follows the interconnect ring (on a v5e 2x2 that is chips 0, 1, 3,
+    2, not JAX's list order); the CPU backend keeps list order."""
     import jax
+    from jax.experimental import mesh_utils
     from jax.sharding import Mesh
 
     if pod:
@@ -39,5 +45,11 @@ def make_test_mesh(dp: int, tp: int, pod: int = 0):
         shape, axes = (dp, tp), ("data", "model")
     need = int(np.prod(shape))
     devs = jax.devices()
-    assert len(devs) >= need, (len(devs), shape)
-    return Mesh(np.asarray(devs[:need]).reshape(shape), axes)
+    if len(devs) < need:
+        raise RuntimeError(
+            f"a {dict(zip(axes, shape))} mesh needs {need} devices, JAX "
+            f"has {len(devs)} {devs[0].platform} device(s); the CPU "
+            f"backend exposes more only when asked before it starts "
+            f"(XLA_FLAGS=--xla_force_host_platform_device_count={need})")
+    return Mesh(mesh_utils.create_device_mesh(shape, devices=devs[:need]),
+                axes)

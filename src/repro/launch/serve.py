@@ -137,6 +137,9 @@ def main():
 
     import numpy as np
     from repro.api import LLM, SamplingParams, SpecConfig
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     # observability (docs/observability.md): an isolated registry +
     # wall-clock tracer, wired through every scheduler / pool / router

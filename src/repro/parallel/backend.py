@@ -197,8 +197,12 @@ class VmapSimBackend(ParallelBackend):
         return jax.jit(fn, donate_argnums=spec.donate)
 
     def place_params(self, stacked: dict):
+        """The split tree on JAX's default device, wherever the stacked
+        tree was built (the host, for `repro.api.LLM`)."""
         from repro.core import simtp
-        return simtp.split_stacked(stacked, self.cfg, self.plan, self.tp)
+        return jax.device_put(
+            simtp.split_stacked(stacked, self.cfg, self.plan, self.tp),
+            jax.devices()[0])
 
     def blank_caches(self, structs, *, shard_batch: bool = True):
         from repro.core import model as M
@@ -272,8 +276,10 @@ class ShardMapBackend(ParallelBackend):
             donate_argnums=spec.donate)
 
     def place_params(self, stacked: dict):
+        """Each device receives only its own shard of every leaf: the
+        stacked tree stays wherever it was built (the host, for
+        `repro.api.LLM`), never whole on one chip."""
         from repro.parallel import tp as TP
-        stacked = jax.tree.map(jnp.array, stacked)
         return jax.device_put(stacked, TP.named(
             self.mesh, TP.param_pspecs(self.cfg, self.plan)))
 
@@ -282,8 +288,8 @@ class ShardMapBackend(ParallelBackend):
         sh = TP.named(self.mesh, TP.cache_pspecs(
             self.cfg, self.plan, self.mesh, shard_batch=shard_batch))
         return [jax.tree.map(
-            lambda s, h: jax.device_put(jnp.zeros(s.shape, s.dtype), h),
-            st, shh) for st, shh in zip(structs, sh)]
+            lambda s, h: jnp.zeros(s.shape, s.dtype, device=h), st, shh)
+            for st, shh in zip(structs, sh)]
 
 
 # ---------------------------------------------------------------------------

@@ -2,21 +2,9 @@
 the backend initializes; smoke tests simply don't use the mesh).  The
 512-device dry-run platform is NEVER set here — dryrun.py owns that in
 its own subprocess."""
-import os
-
 import jax
 
-try:
-    jax.config.update("jax_num_cpu_devices", 8)
-except AttributeError:
-    # Older JAX (e.g. 0.4.x) has no jax_num_cpu_devices config option.
-    # The XLA flag achieves the same thing as long as it is set before the
-    # backend initializes — conftest import runs before any test touches a
-    # device, so this is safe here.
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in flags:
-        os.environ["XLA_FLAGS"] = (
-            flags + " --xla_force_host_platform_device_count=8").strip()
+jax.config.update("jax_num_cpu_devices", 8)
 
 import numpy as np
 import pytest

@@ -138,6 +138,17 @@ def test_generate_greedy_matches_direct_scheduler(llm_sim):
         assert o.prompt_token_ids == [int(t) for t in prompts[i]]
 
 
+def test_generate_drains_queue_when_admissions_finish_at_once():
+    """Requests that finish on their admission token free every slot in
+    the same step; the queued rest must still run (a step that admits
+    and finishes everything used to report no work left)."""
+    cfg = make_cfg("smollm-360m")
+    llm = LLM.load(cfg, tp=1, engine="sim", cache_len=16, max_batch=3)
+    outs = llm.generate([[0]] * 4, SamplingParams(max_new=1))
+    assert [len(o.token_ids) for o in outs] == [1] * 4
+    assert all(o.finish_reason == "length" for o in outs)
+
+
 def test_legacy_server_module_removed():
     """The deprecated `runtime/server.py` Server/PagedServer shims
     (deprecated PR 2, warning since PR 4) are GONE: importing the module

@@ -47,6 +47,34 @@ def test_serve_cli_shard_engine():
     assert all(len(v) >= 4 for v in out["outputs"].values())
 
 
+@pytest.mark.parametrize("env_dir", [None, "/cache/from/env"])
+def test_compile_cache_dir(monkeypatch, env_dir):
+    """$JAX_COMPILATION_CACHE_DIR when set (JAX reads it; nothing else
+    is set), else the fixed `.jax_cache/` at the repository root."""
+    from repro.launch import compile_cache
+
+    set_dirs = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: set_dirs.append((k, v)))
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = os.path.join(ROOT, ".jax_cache")
+        assert compile_cache.enable_compile_cache() == want
+        assert set_dirs == [("jax_compilation_cache_dir", want)]
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+        assert compile_cache.enable_compile_cache() == env_dir
+        assert set_dirs == []
+
+
+def test_test_mesh_needs_enough_devices():
+    from repro.launch.mesh import make_test_mesh
+    n = len(jax.devices())
+    with pytest.raises(RuntimeError, match=f"needs {2 * n} devices"):
+        make_test_mesh(2, n)
+    assert make_test_mesh(1, n).devices.shape == (1, n)
+
+
 @pytest.mark.parametrize("cell", [
     ("smollm-360m", "decode_32k", "single", "0.0"),
     ("hymba-1.5b", "long_500k", "multi", "0.7"),

@@ -21,7 +21,7 @@ from repro.parallel import compression as C
 
 
 @pytest.mark.parametrize("n,levels", [(64, 127), (1000, 127), (4096, 7),
-                                      (777, 7)])
+                                      (777, 7), (38405, 127)])
 def test_qdq_kernel_matches_ref(n, levels):
     from repro.kernels.quant_collectives import qdq_absmax
     x = jnp.asarray(np.random.default_rng(n).standard_normal(n) * 3.0,
@@ -49,6 +49,31 @@ def test_quantize_dequantize_kernels_match_ref(n):
     # round trip error bounded by scale/2 per element
     err = np.abs(np.asarray(y_k) - np.asarray(x))
     assert err.max() <= float(np.max(np.asarray(s_r))) / 2 + 1e-7
+
+
+def test_quant_kernels_multi_block():
+    """A payload taller than one block (301 rows of 128 -> two 256-row
+    blocks, the second zero-padded) round-trips like the oracle: codes
+    exact, scales within one ulp (interpret mode may fold the /levels
+    into a multiply)."""
+    from repro.kernels.quant_collectives import (dequant_accum_absmax,
+                                                 dequantize_absmax,
+                                                 quantize_absmax)
+    n = 300 * 128 + 5
+    rng = np.random.default_rng(n)
+    x = jnp.asarray(rng.standard_normal(n), jnp.float32)
+    acc = jnp.asarray(rng.standard_normal(n), jnp.float32)
+    q_k, s_k = quantize_absmax(x, interpret=True)
+    q_r, s_r = REF.quantize_absmax_ref(x)
+    np.testing.assert_array_equal(np.asarray(q_k), np.asarray(q_r))
+    np.testing.assert_array_max_ulp(np.asarray(s_k), np.asarray(s_r), 1)
+    y_k = dequantize_absmax(q_r, s_r, n=n, interpret=True)
+    y_r = REF.dequantize_absmax_ref(q_r, s_r, n=n)
+    np.testing.assert_allclose(np.asarray(y_k), np.asarray(y_r), rtol=1e-7)
+    a_k = dequant_accum_absmax(q_r, s_r, acc, interpret=True)
+    np.testing.assert_allclose(np.asarray(a_k),
+                               np.asarray(acc) + np.asarray(y_r), rtol=1e-6,
+                               atol=1e-6)
 
 
 # ---------------------------------------------------------------------------
@@ -310,3 +335,74 @@ def test_apply_comm_policy_tiering():
     outs = llm.generate([np.asarray([1, 2, 3], np.int32)],
                         SamplingParams(max_new=4))
     assert len(outs[0].token_ids) == 4
+
+
+# ---------------------------------------------------------------------------
+# Collectives in the compiled program (parallel/hlo.py)
+# ---------------------------------------------------------------------------
+
+# the loop form the TPU compiler emits: no known_trip_count record, the
+# trip count only in the condition's `i < 28`
+_TPU_LOOP_HLO = """
+%add (a: bf16[], b: bf16[]) -> bf16[] {
+  ROOT %s = bf16[] add(%a, %b)
+}
+%cond (p: (s32[], bf16[8])) -> pred[] {
+  %constant.865 = s32[]{:T(128)} constant(28), metadata={op_name="x"}
+  %p = (s32[], bf16[8]) parameter(0)
+  %i = s32[]{:T(128)} get-tuple-element(%p), index=0
+  ROOT %lt.223 = pred[]{:T(512)} compare(%i, %constant.865), direction=LT
+}
+%body (p: (s32[], bf16[8])) -> (s32[], bf16[8]) {
+  %psum.46 = bf16[8]{0:T(128)} all-reduce(%x), channel_id=1, to_apply=%add
+  %psum.47 = bf16[8]{0:T(128)} all-reduce(%y), channel_id=1, to_apply=%add
+}
+ENTRY %main (x: bf16[8]) -> bf16[8] {
+  %while.22 = (s32[], bf16[8]) while(%t), condition=%cond, body=%body
+  %pmax.7 = f32[8]{0:T(128)} all-reduce(%g), channel_id=1, to_apply=%add
+}
+"""
+
+
+def test_collective_counts_reads_loop_trips():
+    from repro.parallel.hlo import collective_counts
+    c = collective_counts(_TPU_LOOP_HLO)["all-reduce"]
+    assert (c["sites"], c["executed"]) == (3, 57)
+    assert c["dtypes"] == {"bf16": 56, "f32": 1}
+    # the CPU compiler's form: known_trip_count on the while itself
+    from jax.sharding import Mesh, PartitionSpec as P
+    mesh = Mesh(np.asarray(jax.devices()[:2]), (MODEL_AXIS,))
+
+    def f(x, w):
+        def body(c, wi):
+            return jax.lax.psum(c @ wi, MODEL_AXIS), None
+        return jax.lax.scan(body, x, w)[0]
+
+    step = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=(P(), P()),
+                                 out_specs=P(), check_vma=False))
+    txt = step.lower(jnp.ones((4, 8)), jnp.ones((5, 8, 8))).compile() \
+        .as_text()
+    c = collective_counts(txt)["all-reduce"]
+    assert (c["sites"], c["executed"]) == (1, 5)
+
+
+def test_spd_plan_executes_fewer_decode_all_reduces():
+    """Each dropped block removes one all-reduce from every decode step
+    the shard backend compiles (the attention-output sync), while the
+    program's call sites grow with the extra scan segment."""
+    from repro.parallel.hlo import collective_counts
+    from repro.runtime import forward as F
+    from conftest import engine_for_backend
+
+    cfg = make_cfg("qwen3-1.7b")
+    counts = {}
+    for k in (0, 2):
+        plan = SPDPlanConfig.first_k(cfg.n_layers, k)
+        eng, params = engine_for_backend("shard", cfg, plan, 2, dp=1)
+        caches = eng.blank_caches(2, 16)
+        step = eng.backend.wrap(*F.decode_step(cfg, plan, tp=2))
+        txt = step.lower(params, jnp.zeros((2, 1), jnp.int32),
+                         jnp.zeros((2,), jnp.int32), caches).compile() \
+            .as_text()
+        counts[k] = collective_counts(txt)["all-reduce"]["executed"]
+    assert counts[0] - counts[2] == 2

@@ -96,6 +96,33 @@ def test_paged_attention_fuzz(dtype):
                                    **_tol(dtype))
 
 
+def test_paged_attention_query_blocks():
+    """A chunk whose heads x positions exceed the kernel's row budget is
+    split into query blocks (grid axis 1); every block must still see
+    its own absolute positions in the causal mask."""
+    from repro.kernels.flash_attention import PAGED_Q_ROWS, _query_block
+    from repro.kernels.ref import paged_attention_ref
+
+    rng = np.random.default_rng(3)
+    b, c, hq, hkv, d, ps, width = 2, 128, 8, 2, 32, 16, 10
+    assert _query_block(c, hq) < c and hq * c > PAGED_Q_ROWS
+    table = np.stack([rng.permutation(2 * width)[:width] for _ in range(b)])
+    table = table.astype(np.int32)
+    table[1, 9:] = -1
+    pos = np.asarray([16, 5], np.int32)
+    q = jnp.asarray(rng.standard_normal((b, c, hq, d)), jnp.float32)
+    kp = jnp.asarray(rng.standard_normal((2 * width + 1, ps, hkv, d)),
+                     jnp.float32)
+    vp = jnp.asarray(rng.standard_normal((2 * width + 1, ps, hkv, d)),
+                     jnp.float32)
+    out = ops.paged_attention(q, kp, vp, jnp.asarray(table),
+                              jnp.asarray(pos), interpret=True)
+    ref = paged_attention_ref(q, kp, vp, jnp.asarray(table),
+                              jnp.asarray(pos))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               **_tol(jnp.float32))
+
+
 def test_paged_scatter_gather_roundtrip():
     """scatter_tokens_pages places every token where gather_pages (the
     legacy dense view) finds it, -1 / out-of-range entries land in the
