@@ -52,7 +52,7 @@ from __future__ import annotations
 
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 import jax.numpy as jnp
 import numpy as np
@@ -402,17 +402,25 @@ class PagedKVCacheManager:
     def release(self, slot: int):
         self.pool.release(slot)
 
+    def _decode_inputs(self, pos):
+        """COW barrier and page table of a decode step, each timed as a
+        span of the pool (`pool.cow`, `pool.table`)."""
+        obs = self.pool.obs
+        with obs.span("pool", "cow"):
+            self._cow(np.asarray(pos), 1)
+        with obs.span("pool", "table"):
+            return self._table()
+
     def decode(self, params, cur, pos):
-        self._cow(np.asarray(pos), 1)
+        table = self._decode_inputs(pos)
         nxt, self.pcaches = self.engine.decode_paged(
-            params, cur, pos, self._table(), self.pcaches)
+            params, cur, pos, table, self.pcaches)
         return nxt
 
     def decode_sampled(self, params, cur, pos, t, k, p, keys):
-        self._cow(np.asarray(pos), 1)
+        table = self._decode_inputs(pos)
         nxt, self.pcaches = self.engine.decode_paged_sampled(
-            params, cur, pos, self._table(), self.pcaches,
-            t, k, p, keys)
+            params, cur, pos, table, self.pcaches, t, k, p, keys)
         return nxt
 
     def verify(self, params, toks, pos, tree=None):
@@ -633,42 +641,24 @@ class Scheduler:
             if m is None:
                 break          # head-of-line: wait for pages, stay FIFO
             self.queue.popleft()
+            # the span covers the admission from its reservation on:
+            # prefill, first-token pull, pool insert; its two clock reads
+            # also bound the queue and prefill slices
+            with self.obs.span("scheduler", "admit") as sp:
+                if self.obs.enabled:
+                    sp.update(uid=req.uid, slot=b, tokens=s - m, cached=m)
+                    meta = self._req_meta.setdefault(
+                        id(req),
+                        {"submit0": sp.t0, "submit": sp.t0, "first": None})
+                    wait = sp.t0 - meta["submit"]
+                    self.obs.observe("queue_wait_seconds", wait)
+                    self.obs.complete(f"slot{b}", "queue", meta["submit"],
+                                      wait, uid=req.uid)
+                first, t_first = self._admit_one(b, req, toks, s, m)
             if self.obs.enabled:
-                t_admit = self.obs.now()
-                meta = self._req_meta.setdefault(
-                    id(req),
-                    {"submit0": t_admit, "submit": t_admit, "first": None})
-                wait = t_admit - meta["submit"]
-                self.obs.observe("queue_wait_seconds", wait)
-                self.obs.complete(f"slot{b}", "queue", meta["submit"],
-                                  wait, uid=req.uid)
-            try:
-                if m:
-                    # warm admission: shared prefix pages are already
-                    # resident — prefill only the uncached suffix in
-                    # place (no dense caches1 / insert round-trip)
-                    logits = self.kv.prefill_suffix(self.params, toks, m, b)
-                else:
-                    logits, caches1 = self._prefill(toks, s)
-                first = self._first_token(req, logits)
-            except BaseException:
-                # admit_begin already reserved pages for slot b — free
-                # them and put the request back so nothing leaks on a
-                # prefill failure (engine error, interrupt, ...)
-                self.kv.release(b)
-                self.queue.appendleft(req)
-                raise
-            req.out.append(first)
-            self.slots[b] = req
-            self.pos[b] = s
-            self.cur[b, 0] = first
-            self.admit_seq[b] = self._seq
-            self._seq += 1
-            if self.obs.enabled:
-                t_first = self.obs.now()
-                meta["serve_start"] = t_admit
-                self.obs.complete(f"slot{b}", "prefill", t_admit,
-                                  t_first - t_admit, uid=req.uid,
+                meta["serve_start"] = sp.t0
+                self.obs.complete(f"slot{b}", "prefill", sp.t0,
+                                  sp.t1 - sp.t0, uid=req.uid,
                                   tokens=s - m, cached=m)
                 if meta["first"] is None:
                     # TTFT is measured once, from the ORIGINAL submit
@@ -679,26 +669,58 @@ class Scheduler:
                 if m:
                     self.obs.inc("prefix_cache_hits_total")
                     self.obs.inc("prefix_tokens_reused_total", m)
-            if not m:
-                self.kv.insert(caches1, b)
-            self.kv.register_prefix(b, toks)
-            if self.spec is not None:
-                # the draft shares weights, not caches — but a COLD
-                # admission just prefilled this exact prompt, and the
-                # drafter can restack that KV onto its own plan instead
-                # of re-prefilling (Drafter.insert documents the
-                # adoption contract; warm admissions have no dense
-                # caches1, so the drafter prefills itself)
-                self._spec_kb[b] = self.spec.k
-                self._spec_rej[b] = 0
-                try:
-                    self.spec.drafter.insert(
-                        b, toks, caches1=None if m else caches1)
-                except TypeError:
-                    # legacy drafter stubs without the adoption kwarg
-                    self.spec.drafter.insert(b, toks)
             if self._stopping(req, first):
                 self._finish(b)
+
+    def _admit_one(self, b: int, req: Request, toks: np.ndarray, s: int,
+                   m: int) -> Tuple[int, Optional[float]]:
+        """Prefill `req` into slot b (its pages reserved, `m` prefix
+        tokens resident), put it in the batch and the pool; returns its
+        first token and, when recording, the clock read once that token
+        is on the host (the TTFT's end: before the pool insert and the
+        drafter's admission)."""
+        try:
+            if m:
+                # warm admission: shared prefix pages are already
+                # resident — prefill only the uncached suffix in place
+                # (no dense caches1 / insert round-trip)
+                logits = self.kv.prefill_suffix(self.params, toks, m, b)
+            else:
+                logits, caches1 = self._prefill(toks, s)
+            first = self._first_token(req, logits)
+        except BaseException:
+            # admit_begin already reserved pages for slot b — free them
+            # and put the request back so nothing leaks on a prefill
+            # failure (engine error, interrupt, ...)
+            self.kv.release(b)
+            self.queue.appendleft(req)
+            raise
+        req.out.append(first)
+        self.slots[b] = req
+        self.pos[b] = s
+        self.cur[b, 0] = first
+        self.admit_seq[b] = self._seq
+        self._seq += 1
+        t_first = self.obs.now() if self.obs.enabled else None
+        if not m:
+            self.kv.insert(caches1, b)
+        self.kv.register_prefix(b, toks)
+        if self.spec is not None:
+            # the draft shares weights, not caches — but a COLD admission
+            # just prefilled this exact prompt, and the drafter can
+            # restack that KV onto its own plan instead of re-prefilling
+            # (Drafter.insert documents the adoption contract; warm
+            # admissions have no dense caches1, so the drafter prefills
+            # itself)
+            self._spec_kb[b] = self.spec.k
+            self._spec_rej[b] = 0
+            try:
+                self.spec.drafter.insert(
+                    b, toks, caches1=None if m else caches1)
+            except TypeError:
+                # legacy drafter stubs without the adoption kwarg
+                self.spec.drafter.insert(b, toks)
+        return first, t_first
 
     @staticmethod
     def _max_new(req: Request) -> int:
@@ -1120,22 +1142,29 @@ class Scheduler:
             return bool(self.queue)
         if self.spec is not None:
             return self._spec_step(active)
-        if self.kv.paged:
-            # growth: each slot writes position pos[b] this step — make
-            # sure its page exists (preemption rules: _grow_active)
-            active = self._grow_active(active,
-                                       lambda b: int(self.pos[b]) + 1)
-            if not active:
-                return bool(self.queue)
-        nxt = np.asarray(self._decode_active(active))
-        for b in active:
-            req = self.slots[b]
-            tok = int(nxt[b, 0])
-            req.out.append(tok)
-            self.pos[b] += 1
-            self.cur[b, 0] = tok
-            if self._stopping(req, tok):
-                self._finish(b)
+        # host phases of a decode step, as spans: `prep` (growth, input
+        # uploads, COW barrier, page table, dispatch), `wait` (blocked on
+        # the device, then the token copy), `commit` (appends, stops)
+        with self.obs.span("scheduler", "prep"):
+            if self.kv.paged:
+                # growth: each slot writes position pos[b] this step —
+                # make sure its page exists (preemption: _grow_active)
+                active = self._grow_active(active,
+                                           lambda b: int(self.pos[b]) + 1)
+                if not active:
+                    return bool(self.queue)
+            nxt = self._decode_active(active)
+        with self.obs.span("scheduler", "wait"):
+            nxt = np.asarray(nxt)
+        with self.obs.span("scheduler", "commit"):
+            for b in active:
+                req = self.slots[b]
+                tok = int(nxt[b, 0])
+                req.out.append(tok)
+                self.pos[b] += 1
+                self.cur[b, 0] = tok
+                if self._stopping(req, tok):
+                    self._finish(b)
         return True
 
     def has_work(self) -> bool:

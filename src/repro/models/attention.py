@@ -153,21 +153,28 @@ def paged_attend(q, k_pool, v_pool, page_table, pos, *,
 
     `anc` (C, C) bool switches the chunk to TREE visibility (tree_mask):
     the C slots at pos..pos+C-1 attend per the ancestor matrix instead
-    of slot order (speculative tree verification)."""
-    b, c = q.shape[:2]
-    pn1, ps, hkv, dh = k_pool.shape
-    n = page_table.shape[1]
-    pt = jnp.where(page_table < 0, pn1 - 1, page_table)
-    kg = jnp.take(k_pool, pt.reshape(-1), axis=0).reshape(b, n * ps, hkv, dh)
-    vg = jnp.take(v_pool, pt.reshape(-1), axis=0).reshape(b, n * ps, hkv, dh)
-    kv_pos = jnp.broadcast_to(jnp.arange(n * ps)[None], (b, n * ps))
-    if anc is None:
-        q_pos = pos[:, None] + jnp.arange(c, dtype=jnp.int32)[None]
-        mask = causal_mask(q_pos, kv_pos)
-    else:
-        mask = tree_mask(pos, anc, kv_pos)
-    mask &= (jnp.repeat(page_table, ps, axis=1) >= 0)[:, None, :]
-    return attend(q, kg, vg, mask, scale)
+    of slot order (speculative tree verification).
+
+    Every op it emits carries the name scope `attn` in its metadata, so a
+    device trace can tell the gather and the attention math from the
+    rest of the step."""
+    with jax.named_scope("attn"):
+        b, c = q.shape[:2]
+        pn1, ps, hkv, dh = k_pool.shape
+        n = page_table.shape[1]
+        pt = jnp.where(page_table < 0, pn1 - 1, page_table)
+        kg = jnp.take(k_pool, pt.reshape(-1),
+                      axis=0).reshape(b, n * ps, hkv, dh)
+        vg = jnp.take(v_pool, pt.reshape(-1),
+                      axis=0).reshape(b, n * ps, hkv, dh)
+        kv_pos = jnp.broadcast_to(jnp.arange(n * ps)[None], (b, n * ps))
+        if anc is None:
+            q_pos = pos[:, None] + jnp.arange(c, dtype=jnp.int32)[None]
+            mask = causal_mask(q_pos, kv_pos)
+        else:
+            mask = tree_mask(pos, anc, kv_pos)
+        mask &= (jnp.repeat(page_table, ps, axis=1) >= 0)[:, None, :]
+        return attend(q, kg, vg, mask, scale)
 
 
 def decode_attend(q, k_cache, v_cache, pos, *, window: int = 0,

@@ -97,7 +97,9 @@ class Recorder(NullRecorder):
     `metrics=None` binds the process-global default registry; pass a
     fresh `MetricsRegistry()` to isolate a run (serve CLI, tests).
     `tracer=None` builds a wall-clock tracer; inject
-    `Tracer(clock=VirtualClock(...))` for deterministic tests."""
+    `Tracer(clock=VirtualClock(...))` for deterministic tests, or
+    `Tracer(profiler=True)` to mirror every span into a `jax.profiler`
+    trace (obs/trace.py)."""
 
     enabled = True
 

@@ -24,15 +24,22 @@ Everything here is host-side bookkeeping: events are plain dicts
 appended to a list; `save()`/`to_dict()` serialize the
 `{"traceEvents": [...]}` wrapper `chrome://tracing` and
 https://ui.perfetto.dev load directly (docs/observability.md).
+
+Profiler mirror: `Tracer(profiler=True)` also enters a
+`jax.profiler.TraceAnnotation` named `"<track>.<name>"` around every
+`span()`, so a `jax.profiler` session records the same spans on its
+`/host:CPU` plane, on the device planes' clock.  Retroactive slices
+(`complete()`) are not mirrored.  jax is imported only under that
+option.
 """
 from __future__ import annotations
 
 import json
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Callable, Dict, List, Optional
 
-__all__ = ["Tracer", "VirtualClock", "emit_comm"]
+__all__ = ["SpanArgs", "Tracer", "VirtualClock", "emit_comm"]
 
 PID = 1                      # one logical process per trace
 
@@ -54,16 +61,36 @@ class VirtualClock:
         self.t += float(dt)
 
 
+class SpanArgs(dict):
+    """What `Tracer.span` yields: the slice's args, plus its start `t0`
+    and, once the span has closed, its end `t1` (tracer seconds), so a
+    caller can derive other slices from the same two clock reads."""
+
+    t0: float = 0.0
+    t1: float = 0.0
+
+
 class Tracer:
     """Append-only trace-event collector (module docstring)."""
 
-    def __init__(self, clock: Optional[Callable[[], float]] = None):
+    def __init__(self, clock: Optional[Callable[[], float]] = None,
+                 profiler: bool = False):
         self._clock = clock if clock is not None else time.perf_counter
         self._t0 = self._clock()
         self.events: List[dict] = []
         self._tids: Dict[str, int] = {}
+        self._annotation = None
+        if profiler:
+            from jax.profiler import TraceAnnotation
+            self._annotation = TraceAnnotation
 
     # ---------------- time ----------------
+
+    @property
+    def origin(self) -> float:
+        """The clock's reading at construction: event `ts` 0 is this
+        instant on the injected clock."""
+        return self._t0
 
     def now(self) -> float:
         """Seconds since tracer construction (the span-math timebase)."""
@@ -123,13 +150,20 @@ class Tracer:
     @contextmanager
     def span(self, track: str, name: str, **args):
         """Measure the enclosed block as a complete slice.  Yields a
-        dict merged into the slice args at exit (annotate results)."""
-        t0 = self.now()
-        out: dict = dict(args)
-        try:
-            yield out
-        finally:
-            self.complete(track, name, t0, self.now() - t0, out or None)
+        `SpanArgs` dict merged into the slice args at exit (annotate
+        results).  Under `profiler=True` the block also runs inside a
+        `TraceAnnotation("<track>.<name>")`."""
+        ann = (self._annotation(f"{track}.{name}") if self._annotation
+               else nullcontext())
+        with ann:
+            out = SpanArgs(args)
+            out.t0 = self.now()
+            try:
+                yield out
+            finally:
+                out.t1 = self.now()
+                self.complete(track, name, out.t0, out.t1 - out.t0,
+                              dict(out) or None)
 
     # ---------------- export ----------------
 

@@ -115,6 +115,13 @@ class ParallelBackend:
         raise NotImplementedError
 
 
+def _named_as(fn, local_fn):
+    """`fn` under `local_fn`'s name: `jax.jit` names the compiled module
+    after the function it is given, and a wrapper keeps the step's."""
+    fn.__name__ = fn.__qualname__ = local_fn.__name__
+    return fn
+
+
 # ---------------------------------------------------------------------------
 # Registry
 # ---------------------------------------------------------------------------
@@ -194,7 +201,7 @@ class VmapSimBackend(ParallelBackend):
                          else jax.tree.map(lambda x: x[0], o)
                          for o, k in zip(outs, spec.out_kinds))
 
-        return jax.jit(fn, donate_argnums=spec.donate)
+        return jax.jit(_named_as(fn, local_fn), donate_argnums=spec.donate)
 
     def place_params(self, stacked: dict):
         """The split tree on JAX's default device, wherever the stacked
@@ -332,4 +339,4 @@ class OverlapBackend(ShardMapBackend):
             with overlap_region(self.ring_chunks):
                 return local_fn(*args)
 
-        return super().wrap(overlapped, spec)
+        return super().wrap(_named_as(overlapped, local_fn), spec)

@@ -435,16 +435,23 @@ def sync_output(x, axis=MODEL_AXIS, compressible: bool = True, mode=None):
     THIS is the op SPD drops.  `mode` is the block's kept-sync level from
     its CommPolicy ("exact" | "quant8" | "quant4"; None defers to the
     sync_compression context).  `compressible=False` pins exact reduction
-    (embedding lookup, CE softmax sums — tiny payloads, precision-bound)."""
+    (embedding lookup, CE softmax sums — tiny payloads, precision-bound).
+    Its ops carry the name scope `sync.b<block>`, `<block>` being the
+    first block of the segment scan it is traced in (`comm_context`), or
+    `sync` outside any segment, so a device trace can find each kept
+    sync: inside one execution of a segment's layer scan the k-th event
+    of a scoped op is block <block> + k."""
     m = mode if mode is not None else _SYNC.mode
-    if compressible and m in _MODE_BITS:
-        from repro.parallel.compression import quantized_psum
-        return quantized_psum(x, axis, bits=_MODE_BITS[m])
-    # a compressible kept sync is exactly the class of collective the
-    # overlap backend can double-buffer against block compute; pinned
-    # exact reductions (embedding, CE) are serial by construction
-    _log("all-reduce", axis, x, overlappable=compressible)
-    return g_psum(x, axis)
+    blk = _COMM_CTX.block
+    with jax.named_scope("sync" if blk < 0 else f"sync.b{blk}"):
+        if compressible and m in _MODE_BITS:
+            from repro.parallel.compression import quantized_psum
+            return quantized_psum(x, axis, bits=_MODE_BITS[m])
+        # a compressible kept sync is exactly the class of collective the
+        # overlap backend can double-buffer against block compute; pinned
+        # exact reductions (embedding, CE) are serial by construction
+        _log("all-reduce", axis, x, overlappable=compressible)
+        return g_psum(x, axis)
 
 
 def column_entry(x, axis=MODEL_AXIS):

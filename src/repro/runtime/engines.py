@@ -52,8 +52,13 @@ class Engine:
         self._steps = {}
 
     def _step(self, key, builder):
+        """The jitted step under `key`, built on first use.  Its module
+        is named after the key (`jit_decode_paged`, `jit_prefill_chunk`,
+        ...), so a profiler trace names each program by what it is."""
         if key not in self._steps:
-            self._steps[key] = self.backend.wrap(*builder())
+            local_fn, spec = builder()
+            local_fn.__name__ = local_fn.__qualname__ = key[0]
+            self._steps[key] = self.backend.wrap(local_fn, spec)
         return self._steps[key]
 
     # ---- cache trees (backend-native layout) ----

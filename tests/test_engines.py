@@ -11,6 +11,7 @@ import pytest
 from conftest import dp_for, engine_for_backend, make_batch, make_cfg
 from repro.config.base import SPDPlanConfig
 from repro.core import model as M, simtp
+from repro.core.layer_kinds import plan_segments
 from repro.launch.mesh import make_test_mesh
 from repro.parallel import tp as TP
 from repro.parallel.backend import backend_names
@@ -136,3 +137,54 @@ def test_multipod_mesh_axes():
     np.testing.assert_allclose(float(met["loss"]),
                                float(m["sum_ce"] / m["n_tok"]),
                                rtol=2e-5)
+
+
+@pytest.mark.parametrize("backend_name", backend_names())
+def test_step_modules_named_after_their_key(backend_name):
+    """Every jitted step's module is named after its engine key, so a
+    profiler trace tells the programs apart by name."""
+    import re
+
+    from repro.runtime import forward as F
+
+    cfg = make_cfg("qwen3-1.7b")
+    plan = SPDPlanConfig.first_k(cfg.n_layers, 1)
+    eng, params = engine_for_backend(backend_name, cfg, plan, 2, dp=1)
+    pc = eng.blank_paged_caches(4, 32, page_size=8, num_pages=12)
+    pos = jnp.zeros(4, jnp.int32)
+    table = jnp.full((4, 2), -1, jnp.int32)
+    lowered = {
+        "decode_paged": eng._decode_paged(False).lower(
+            params, jnp.zeros((4, 1), jnp.int32), pos, table, pc),
+        "insert_paged": eng._step(
+            ("insert_paged",),
+            lambda: F.insert_paged_step(eng.cfg, eng.plan)).lower(
+            pc, eng.blank_caches(1, 32, replicated=True), jnp.int32(0),
+            jnp.full(4, -1, jnp.int32)),
+    }
+    for key, low in lowered.items():
+        assert re.search(r"module @(\S+)", low.as_text()).group(1) \
+            == f"jit_{key}"
+
+
+def test_decode_ops_carry_attn_and_sync_scopes():
+    """The paged decode's attention ops carry the `attn` name scope and
+    the kept syncs `sync.b<first block of their segment>` (`sync` outside
+    the layer scans), in the compiled module's op metadata."""
+    import re
+
+    cfg = make_cfg("qwen3-1.7b")
+    plan = SPDPlanConfig.first_k(cfg.n_layers, 1)
+    eng, params = engine_for_backend("shard", cfg, plan, 2, dp=1)
+    pc = eng.blank_paged_caches(4, 32, page_size=8, num_pages=12)
+    txt = eng._decode_paged(False).lower(
+        params, jnp.zeros((4, 1), jnp.int32), jnp.zeros(4, jnp.int32),
+        jnp.full((4, 2), -1, jnp.int32), pc).compile().as_text()
+    scopes = [n.split("/") for n in re.findall(r'op_name="([^"]*)"', txt)]
+    assert sum("attn" in s for s in scopes) > 0
+    assert sum("sync" in s for s in scopes) > 0   # embedding lookup
+    starts = {seg[0] for seg in plan_segments(cfg, plan.drop_mask,
+                                              plan.qmodes)}
+    blocks = {int(n[len("sync.b"):]) for s in scopes for n in s
+              if n.startswith("sync.b")}
+    assert blocks == starts

@@ -383,3 +383,147 @@ def test_warmup_is_obs_invisible():
     assert rep.sched.obs is obs                  # recorder restored
     assert rep.sched.pool.obs is obs
     assert rep.sched.pool.high_water == 0        # canonical restore
+
+
+# ---------------------------------------------------------------------------
+# Host-phase spans and the profiler mirror (Tracer(profiler=True))
+# ---------------------------------------------------------------------------
+
+
+def _span_tree(tracer):
+    """[(name, children)] of the top-level complete slices, each child
+    named `<track>.<name>`; parents are found by time containment (the
+    virtual clock advances on every read, so nesting is strict)."""
+    tracks = {e["tid"]: e["args"]["name"] for e in tracer.events
+              if e["ph"] == "M" and e["name"] == "thread_name"}
+    xs = sorted(((f"{tracks[e['tid']]}.{e['name']}", e["ts"],
+                  e["ts"] + e["dur"]) for e in tracer.events
+                 if e["ph"] == "X" and not tracks[e["tid"]].startswith(
+                     "slot")), key=lambda t: (t[1], -t[2]))
+    root = ("root", -1.0, float("inf"), [])
+    stack = [root]
+    for n, s, e in xs:
+        while not (stack[-1][1] <= s and e <= stack[-1][2]):
+            stack.pop()
+        node = (n, s, e, [])
+        stack[-1][3].append(node)
+        stack.append(node)
+    return root[3]
+
+
+def test_decode_step_span_tree():
+    """Every decode step is step ⊃ {admit*, prep ⊃ {pool.cow, pool.table},
+    wait, commit}, with one `admit` per admission (re-admissions after a
+    preemption included)."""
+    obs = Recorder(MetricsRegistry(), Tracer(clock=VirtualClock(tick=1e-3)))
+    sched, reqs = _run(obs, n=5, max_new=10, num_pages=6)
+    assert sched.n_preemptions > 0
+    steps = _span_tree(obs.tracer)
+    assert steps and {s[0] for s in steps} == {"scheduler.step"}
+    admits = decodes = 0
+    for _, _, _, kids in steps:
+        names = [k[0] for k in kids]
+        n_admit = names.count("scheduler.admit")
+        admits += n_admit
+        assert names[:n_admit] == ["scheduler.admit"] * n_admit
+        if names[n_admit:]:
+            decodes += 1
+            assert names[n_admit:] == ["scheduler.prep", "scheduler.wait",
+                                       "scheduler.commit"]
+            prep = kids[n_admit]
+            assert [k[0] for k in prep[3]] == ["pool.cow", "pool.table"]
+        for k in kids:
+            if k[0] != "scheduler.prep":
+                assert k[3] == []                # leaves
+    assert decodes > 0
+    assert admits == len(reqs) + sched.n_preemptions
+    assert admits == obs.snapshot()["queue_wait_seconds_count"]
+
+
+def test_admit_span_bounds_prefill_slice():
+    """The slot's prefill slice and the admit span are the same two
+    clock reads; the admit span carries the admission's args."""
+    obs = Recorder(MetricsRegistry(), Tracer(clock=VirtualClock(tick=1e-3)))
+    _run(obs, n=3)
+    ev = obs.tracer.events
+    admits = [e for e in ev if e["ph"] == "X" and e["name"] == "admit"]
+    prefills = [e for e in ev if e["ph"] == "X" and e["name"] == "prefill"]
+    assert len(admits) == len(prefills) == 3
+    for a, p in zip(admits, prefills):
+        assert (a["ts"], a["dur"]) == (p["ts"], p["dur"])
+        assert a["args"]["uid"] == p["args"]["uid"]
+        assert set(a["args"]) == {"uid", "slot", "tokens", "cached"}
+
+
+def test_ttft_ends_at_first_token_not_pool_insert():
+    """TTFT stops once the first token is on the host; the admit span
+    (and the slot's prefill slice) runs on through the pool insert."""
+    clock = VirtualClock()
+    obs = Recorder(MetricsRegistry(), Tracer(clock=clock))
+    cc = CacheConfig(cache_len=32, max_batch=2, page_size=4, num_pages=8)
+    sched = Scheduler(FakeEngine(), None, cc, obs=obs)
+    insert = sched.kv.insert
+
+    def slow_insert(*a, **k):
+        clock.advance(1.0)
+        return insert(*a, **k)
+
+    sched.kv.insert = slow_insert
+    sched.submit(mk_requests(1, seed=3, max_new=3)[0])
+    sched.run()
+    snap = obs.snapshot()
+    assert snap["ttft_seconds_count"] == 1
+    assert snap["ttft_seconds_sum"] == 0.0
+    admit, = [e for e in obs.tracer.events
+              if e["ph"] == "X" and e["name"] == "admit"]
+    assert admit["dur"] >= 1e6                   # us: the insert's second
+
+
+def test_profiler_recorder_token_parity():
+    obs = Recorder(MetricsRegistry(), Tracer(profiler=True))
+    on, reqs_on = _run(obs, n=5, max_new=10, num_pages=6)
+    off, reqs_off = _run(None, n=5, max_new=10, num_pages=6)
+    assert [r.out for r in reqs_on] == [r.out for r in reqs_off]
+    assert on.n_preemptions == off.n_preemptions
+
+
+def test_profiler_session_holds_scheduler_spans(tmp_path):
+    """A real jax.profiler session records the mirrored spans on the
+    `/host:CPU` plane, nested as the in-memory ones are."""
+    import glob
+
+    import jax
+
+    obs = Recorder(MetricsRegistry(), Tracer(profiler=True))
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        _run(obs, n=3)
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)[-1]
+    pd = jax.profiler.ProfileData.from_file(path)
+    host = [p for p in pd.planes if p.name == "/host:CPU"][0]
+    evs = [(e.name, e.start_ns, e.end_ns) for line in host.lines
+           for e in line.events
+           if e.name.startswith(("scheduler.", "pool."))]
+    names = {n for n, _, _ in evs}
+    assert names == {"scheduler.step", "scheduler.admit", "scheduler.prep",
+                     "scheduler.wait", "scheduler.commit", "pool.cow",
+                     "pool.table"}
+    n_x = sum(1 for e in obs.tracer.events if e["ph"] == "X"
+              and e["name"] in ("step", "admit", "prep", "wait", "commit",
+                                "cow", "table"))
+    assert len(evs) == n_x                       # every span mirrored once
+    steps = [(s, e) for n, s, e in evs if n == "scheduler.step"]
+    for n, s, e in evs:
+        if n == "scheduler.wait":
+            assert any(a <= s and e <= b for a, b in steps)
+
+
+def test_obs_import_stays_jax_free():
+    import subprocess
+    import sys
+    code = ("import sys; from repro.obs import Recorder, Tracer; "
+            "Recorder(tracer=Tracer()); assert 'jax' not in sys.modules; "
+            "Tracer(profiler=True); assert 'jax' in sys.modules")
+    subprocess.run([sys.executable, "-c", code], check=True, timeout=120)
