@@ -1000,22 +1000,26 @@ def block_ext(cfg, kind, lay, p, x, pos, cache, *, drop: bool, tp: int,
 
 
 # ---------------------------------------------------------------------------
-# Paged-cache mode: the per-layer K/V caches are physical page POOLS
-# (P+1, ps, HkvL, dh) shared across slots, indexed through a page table —
-# no contiguous per-slot view is ever materialized.  New tokens scatter
-# straight into their pages; attention reads K/V through the table (fused
-# Pallas kernel on attn_backend="pallas", else the gather-only-the-table
-# XLA path whose numerics are bit-identical to dense decode).  GQA
-# full-causal fp-cache layers only (model.supports_paged_attention gates
-# callers); other archs use the legacy gather/scatter fallback in
-# runtime/forward.py.
+# Paged-cache mode: a segment's K/V caches are stacked physical page POOLS
+# (L, P+1, ps, HkvL, dh) shared across slots, indexed through a page
+# table — no contiguous per-slot view is ever materialized.  The layer
+# scan (model.paged_step) carries the stacked pools, and layer `l` works
+# on them in place: new tokens scatter straight into their pages at
+# (l, page, offset), and attention reads layer l's K/V through the table
+# (fused Pallas kernel on attn_backend="pallas", else the
+# gather-only-the-table XLA path whose numerics are bit-identical to
+# dense decode).  On the XLA path no pool-sized buffer is sliced out,
+# copied or written back per step.  GQA full-causal fp-cache layers only
+# (model.supports_paged_attention gates callers); other archs use the
+# legacy gather/scatter fallback in runtime/forward.py.
 # ---------------------------------------------------------------------------
 
 
-def gqa_mixer_page(cfg, kind, a, h, pos, cache, page_table, lay, axis,
-                   depths=None, anc=None):
+def gqa_mixer_page(cfg, kind, a, h, pos, cache, layer, page_table, lay,
+                   axis, depths=None, anc=None):
     """Paged attention over a chunk: h (B,C,d); pos (B,) absolute start
-    position of each slot's chunk; cache {"k","v"} page pools.
+    position of each slot's chunk; cache {"k","v"} the segment's stacked
+    page pools, of which this is layer `layer` (updated in place).
 
     Tree mode: `depths` (C,) replaces the contiguous chunk offsets for
     RoPE (token j sits at tree position pos+depths[j]) and `anc` (C,C)
@@ -1031,30 +1035,40 @@ def gqa_mixer_page(cfg, kind, a, h, pos, cache, page_table, lay, axis,
         pos2 = pos[:, None] + depths[None]
     q = apply_rope(q, pos2, cfg.rope_theta, cfg.rope_fraction)
     k = apply_rope(k, pos2, cfg.rope_theta, cfg.rope_fraction)
-    cache = {"k": KOPS.scatter_tokens_pages(cache["k"], k, page_table, pos),
-             "v": KOPS.scatter_tokens_pages(cache["v"], v, page_table, pos)}
+    cache = {"k": KOPS.scatter_tokens_pages(cache["k"], layer, k,
+                                            page_table, pos),
+             "v": KOPS.scatter_tokens_pages(cache["v"], layer, v,
+                                            page_table, pos)}
     if cfg.attn_backend == "pallas" and anc is None:
         import jax as _jax
         interp = _jax.default_backend() != "tpu"
-        o = KOPS.paged_attention(q, cache["k"], cache["v"], page_table, pos,
-                                 interpret=interp)
+        o = KOPS.paged_attention(q, cache["k"][layer], cache["v"][layer],
+                                 page_table, pos, interpret=interp)
     else:
-        o = A.paged_attend(q, cache["k"], cache["v"], page_table, pos,
-                           anc=anc)
+        # read layer `layer`'s pages through a flat (L*(P+1)) page index
+        # into the stacked pools: indexing `pool[layer]` first makes XLA
+        # materialize the layer's whole pool on the TPU.  Unallocated
+        # (-1) entries stay -1, so paged_attend masks them as before.
+        pn1 = cache["k"].shape[1]
+        flat = jnp.where(page_table < 0, -1, page_table + layer * pn1)
+        k_flat, v_flat = (cache[n].reshape((-1,) + cache[n].shape[2:])
+                          for n in ("k", "v"))
+        o = A.paged_attend(q, k_flat, v_flat, flat, pos, anc=anc)
     b, c = h.shape[:2]
     part = _mm(o.reshape(b, c, -1), a["wo"])
     return part, cache
 
 
-def block_page(cfg, kind, lay, p, x, pos, cache, page_table, *, drop: bool,
-               tp: int, shard_idx, axis=MODEL_AXIS, comm=None, depths=None,
-               anc=None):
+def block_page(cfg, kind, lay, p, x, pos, cache, layer, page_table, *,
+               drop: bool, tp: int, shard_idx, axis=MODEL_AXIS, comm=None,
+               depths=None, anc=None):
     """Paged-cache block (decode C=1 or chunked-prefill extension C>1):
-    x (B,C,d), pos (B,) chunk starts.  Returns (out, cache)."""
+    x (B,C,d), pos (B,) chunk starts; cache the segment's stacked page
+    pools, `layer` this block's index into them.  Returns (out, cache)."""
     assert kind.mixer == "gqa" and kind.window == 0, kind
     h = _norm(x, p["ln1"], cfg, shared=False, axis=axis)
     h = column_entry(h, axis)
-    part, cache = gqa_mixer_page(cfg, kind, p["attn"], h, pos, cache,
+    part, cache = gqa_mixer_page(cfg, kind, p["attn"], h, pos, cache, layer,
                                  page_table, lay, axis, depths=depths,
                                  anc=anc)
     out = _wire_post_mixer(cfg, kind, p, x, part, p["attn"].get("bo"),

@@ -675,11 +675,15 @@ def paged_step(cfg, stacked, plan, tokens, pos, caches, page_table, *, tp,
     tokens (B, C) at per-row absolute positions pos (B,); caches per
     segment hold paged K/V pools (length, P+1, ps, HkvL, dh) shared
     across slots; page_table (B, n) int32 (-1 = unallocated) maps logical
-    page j of slot b to a physical page.  New K/V scatter straight into
-    the slot's pages and attention reads through the table
-    (blocks.gqa_mixer_page) — no contiguous per-slot cache view is ever
-    materialized.  Returns (logits (B, C, Vl) fp32 shard-local — entry j
-    scores the token after tokens[:, j] — and the updated caches).
+    page j of slot b to a physical page.  Each segment's layer scan
+    carries its stacked pools and updates them in place: layer l
+    scatters its new K/V straight into the slot's pages at (l, page,
+    offset) and attention reads layer l's pages through the table
+    (blocks.gqa_mixer_page), so neither a contiguous per-slot cache view
+    nor a per-layer copy of a pool is ever materialized; the carried
+    pools come out as the new caches (donated in, aliased out).
+    Returns (logits (B, C, Vl) fp32 shard-local — entry j scores the
+    token after tokens[:, j] — and the updated caches).
 
     Rollback contract matches verify_step: rejected-suffix K/V stays in
     the slot's pages but is never causally visible, and is overwritten
@@ -704,19 +708,20 @@ def paged_step(cfg, stacked, plan, tokens, pos, caches, page_table, *, tp,
     new_caches = []
     for seg_i, (s0, length, kind, dropped) in enumerate(segs):
         sp = stacked["segs"][seg_i]
-        cache_seg = caches[seg_i]
 
-        def body(xc, xs_i, kind=kind, dropped=dropped,
+        def body(carry, xs_i, kind=kind, dropped=dropped,
                  comm=plan.block_mode(s0)):
-            layer_p, cache = xs_i
-            out, nc = B.block_page(cfg, kind, lay, layer_p, xc, pos, cache,
-                                   page_table, drop=dropped, tp=tp,
-                                   shard_idx=shard_idx, axis=axis, comm=comm,
-                                   depths=depths, anc=anc)
-            return out, nc
+            xc, pools = carry
+            layer_p, layer = xs_i
+            out, pools = B.block_page(cfg, kind, lay, layer_p, xc, pos,
+                                      pools, layer, page_table, drop=dropped,
+                                      tp=tp, shard_idx=shard_idx, axis=axis,
+                                      comm=comm, depths=depths, anc=anc)
+            return (out, pools), None
 
         with ledger_scale(length), comm_context(block=s0, phase="decode"):
-            x, nc = jax.lax.scan(body, x, (sp, cache_seg))
+            (x, nc), _ = jax.lax.scan(body, (x, caches[seg_i]),
+                                      (sp, jnp.arange(length)))
         new_caches.append(nc)
     x = (layernorm(x, stacked["lnf"]["w"], stacked["lnf"]["b"], cfg.norm_eps)
          if cfg.norm == "layernorm"

@@ -106,18 +106,21 @@ def paged_attention(q, k_pool, v_pool, page_table, pos, *, sm_scale=None,
                                     sm_scale=sm_scale, interpret=interpret)
 
 
-def scatter_tokens_pages(pool, vals, page_table, pos):
+def scatter_tokens_pages(pool, layer, vals, page_table, pos):
     """Write a chunk of C tokens per slot straight into its pages.
 
-    pool (P+1, ps, *t) is ONE layer's physical page pool (no batch
-    axis); vals (B, C, *t) are the new entries for logical positions
+    pool (L, P+1, ps, *t) is a segment's stacked physical page pools (no
+    batch axis) and `layer` the (traced) index of the layer being
+    written; vals (B, C, *t) are the new entries for logical positions
     pos[b]..pos[b]+C-1 of slot b.  Positions whose table entry is -1 (or
     that fall beyond the table width — inactive slots carry garbage pos)
-    land in the trash page.  One vectorized scatter: distinct positions
-    of a slot never collide on (page, offset), distinct slots never
-    share a live page, so only trash-page writes overlap (don't care)."""
-    pn = pool.shape[0] - 1
-    ps = pool.shape[1]
+    land in that layer's trash page.  One vectorized scatter of B*C rows
+    into the stacked pool, so a layer scan that carries the pool updates
+    it in place: distinct positions of a slot never collide on (page,
+    offset), distinct slots never share a live page, so only trash-page
+    writes overlap (don't care)."""
+    pn = pool.shape[1] - 1
+    ps = pool.shape[2]
     b, c = vals.shape[:2]
     n = page_table.shape[1]
     pos2 = pos[:, None] + jnp.arange(c, dtype=jnp.int32)[None]   # (B, C)
@@ -125,7 +128,7 @@ def scatter_tokens_pages(pool, vals, page_table, pos):
     phys = jnp.take_along_axis(page_table, jnp.clip(pidx, 0, n - 1), 1)
     phys = jnp.where((phys < 0) | (pidx >= n) | (pidx < 0), pn, phys)
     off = pos2 % ps
-    return pool.at[phys.reshape(-1), off.reshape(-1)].set(
+    return pool.at[layer, phys.reshape(-1), off.reshape(-1)].set(
         vals.reshape((b * c,) + vals.shape[2:]))
 
 

@@ -123,20 +123,23 @@ def test_paged_attention_query_blocks():
                                **_tol(jnp.float32))
 
 
-def test_paged_scatter_gather_roundtrip():
-    """scatter_tokens_pages places every token where gather_pages (the
-    legacy dense view) finds it, -1 / out-of-range entries land in the
-    trash page, and live pages of other slots are untouched."""
+@pytest.mark.parametrize("n_layers,layer", [(1, 0), (3, 1), (3, 2)])
+def test_paged_scatter_gather_roundtrip(n_layers, layer):
+    """scatter_tokens_pages places every token of the stacked pool's
+    layer `layer` where gather_pages (the legacy dense view) finds it,
+    -1 / out-of-range entries land in that layer's trash page, live
+    pages of other slots are untouched, and so is every other layer."""
     rng = np.random.default_rng(7)
     ps, phys, width, b, c, tail = 4, 6, 3, 2, 3, (2, 5)
-    pool = jnp.zeros((phys + 1, ps) + tail, jnp.float32)
+    pool = jnp.zeros((n_layers, phys + 1, ps) + tail, jnp.float32)
     table = np.asarray([[0, 3, -1], [5, -1, -1]], np.int32)
     pos = np.asarray([2, 1], np.int32)
     vals = jnp.asarray(rng.standard_normal((b, c) + tail), jnp.float32)
-    out = ops.scatter_tokens_pages(pool, vals, jnp.asarray(table),
-                                   jnp.asarray(pos))
-    dense = np.asarray(out)[np.where(table < 0, phys, table)]  # (B, W, ps)
-    dense = dense.reshape(b, width * ps, *tail)
+    out = np.asarray(ops.scatter_tokens_pages(
+        pool, jnp.int32(layer), vals, jnp.asarray(table), jnp.asarray(pos)))
+    assert np.delete(out, layer, axis=0).sum() == 0   # other layers
+    dense = np.asarray(ops.gather_pages(jnp.asarray(out),
+                                        jnp.asarray(table)))[layer]
     for r in range(b):
         for j in range(c):
             p = int(pos[r]) + j
@@ -145,12 +148,13 @@ def test_paged_scatter_gather_roundtrip():
                                               np.asarray(vals)[r, j])
     # slot 0 wrote positions 2..4: page 0 offsets 2,3 + page 3 offset 0;
     # nothing past its own chunk is touched
-    assert np.asarray(out)[3, 1:].sum() == 0
-    # a write through a -1 entry must hit ONLY the trash page
+    assert out[layer, 3, 1:].sum() == 0
+    # a write through a -1 entry must hit ONLY the layer's trash page
     table2 = np.asarray([[-1, -1, -1], [5, -1, -1]], np.int32)
-    out2 = ops.scatter_tokens_pages(pool, vals, jnp.asarray(table2),
-                                    jnp.asarray(pos))
-    assert np.asarray(out2)[:5].sum() == 0        # pages 0..4 untouched
+    out2 = np.asarray(ops.scatter_tokens_pages(
+        pool, jnp.int32(layer), vals, jnp.asarray(table2), jnp.asarray(pos)))
+    assert out2[layer, :5].sum() == 0             # pages 0..4 untouched
+    assert np.delete(out2, layer, axis=0).sum() == 0
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

@@ -467,6 +467,95 @@ def test_paged_equals_dense(spd, backend_name):
     _drive_equiv(eng, placed, cfg, n_slots=4)
 
 
+def _pool_rows_changed(before, after, table, pos, n):
+    """Check every layer of every paged leaf: rows (page, offset) that
+    differ between two pool snapshots are exactly the written ones —
+    positions pos[b]..pos[b]+n-1 of each slot with a live page there —
+    or lie in the trash page.  Returns the written (slot, position,
+    page, offset) tuples."""
+    ps, trash = PS, NPG
+    written = [(b, p, int(table[b, p // ps]), p % ps)
+               for b in range(table.shape[0])
+               for p in range(int(pos[b]), int(pos[b]) + n)
+               if p // ps < table.shape[1] and table[b, p // ps] >= 0]
+    rows = {(pg, off) for _, _, pg, off in written}
+    for seg0, seg1 in zip(before, after):
+        for name in ("k", "v"):
+            a, c = np.asarray(seg0[name]), np.asarray(seg1[name])
+            diff = np.any(a != c, axis=tuple(range(3, a.ndim)))  # (L,P+1,ps)
+            for layer in range(a.shape[0]):
+                changed = {(int(pg), int(off))
+                           for pg, off in zip(*np.nonzero(diff[layer]))
+                           if pg != trash}
+                assert changed == rows, (name, layer, changed ^ rows)
+    return written
+
+
+@pytest.mark.parametrize("tp", [1, 4], ids=lambda t: f"tp{t}")
+def test_paged_step_writes_only_its_rows(tp):
+    """The layer scans carry the stacked page pools and update them in
+    place: after one decode step and one 3-token verify chunk, every
+    layer of every SPD segment differs from its input only at the rows
+    written (plus the trash page), each written row holds what the dense
+    path holds at the same layer and position, and the logits equal the
+    dense path's.  The plan drops blocks 1-2 of 4, so three segments'
+    scans each index their pools from 0: a segment-local vs global
+    layer index mix-up shows here."""
+    cfg = make_cfg("smollm-360m")
+    plan = SPDPlanConfig((False, True, True, False))
+    assert len(plan.segments()) == 3
+    eng, params = engine_for_backend("shard", cfg, plan, tp, dp=1)
+    n_slots, prompts = 4, _prompts(cfg)
+    nb = len(prompts)
+    dense = eng.blank_caches(n_slots, CACHE)
+    pool = PagePool(num_pages=NPG, page_size=PS, max_slots=n_slots,
+                    pages_per_slot=CACHE // PS)
+    pc = eng.blank_paged_caches(n_slots, CACHE, page_size=PS,
+                                num_pages=NPG)
+    pos = np.zeros(n_slots, np.int32)
+    cur = np.zeros((n_slots, 1), np.int32)
+    for b, p in enumerate(prompts):
+        toks = np.zeros((1, 32), np.int32)
+        toks[0, :len(p)] = p
+        lg, c1 = eng.prefill(params, jnp.asarray(toks), cache_len=CACHE,
+                             lengths=jnp.asarray([len(p)], jnp.int32))
+        dense = eng.insert_slot(dense, c1, b)
+        assert pool.grow(b, len(p) + 4)
+        pc = eng.insert_paged(pc, c1, b, pool.table[b])
+        pos[b] = len(p)
+        cur[b, 0] = int(np.argmax(np.asarray(lg)[0]))
+    table = np.asarray(pool.table)
+
+    def check(before, after, dense_after, n, lg_dense, lg_paged):
+        np.testing.assert_allclose(np.asarray(lg_dense)[:nb],
+                                   np.asarray(lg_paged)[:nb],
+                                   atol=2e-4, rtol=2e-4)
+        written = _pool_rows_changed(before, after, table, pos, n)
+        assert {b for b, *_ in written} == set(range(nb))
+        for seg1, segd in zip(after, dense_after):
+            for name in ("k", "v"):
+                a, d = np.asarray(seg1[name]), np.asarray(segd[name])
+                for b, p, pg, off in written:
+                    np.testing.assert_allclose(a[:, pg, off], d[:, b, p],
+                                               atol=2e-4, rtol=2e-4)
+
+    snap = jax.tree.map(np.asarray, pc)
+    _, l1, dense = eng.decode_with_logits(
+        params, jnp.asarray(cur), jnp.asarray(pos), dense)
+    _, l2, pc = eng.decode_paged_with_logits(
+        params, jnp.asarray(cur), jnp.asarray(pos), jnp.asarray(table), pc)
+    check(snap, pc, dense, 1, l1, l2)
+    pos[:nb] += 1
+    chunk = np.random.default_rng(5).integers(
+        0, cfg.vocab_size, (n_slots, 3)).astype(np.int32)
+    snap = jax.tree.map(np.asarray, pc)
+    l1, dense = eng.verify(params, jnp.asarray(chunk), jnp.asarray(pos),
+                           dense)
+    l2, pc = eng.verify_paged(params, jnp.asarray(chunk), jnp.asarray(pos),
+                              jnp.asarray(table), pc)
+    check(snap, pc, dense, 3, l1, l2)
+
+
 # ---------------------------------------------------------------------------
 # Chunked prefill == one-shot prefill
 # ---------------------------------------------------------------------------

@@ -4,19 +4,30 @@ heads at TP=1, 4 / 2 at TP=4, d_model 2048).  The TPU compiler refuses
 what interpret mode accepts — block shapes off the (8, 128) tiling,
 kernels that overrun the scoped VMEM — so these tests catch it without a
 chip.  Nothing runs: a pass means the chip's compiler accepted the
-kernel, not that its results or speed are right.
+kernel, not that its results or speed are right.  One whole paged
+decode step is compiled too, to check from its HLO and memory analysis
+that the page pools are updated in place.
 
 The topology is described inside a fixture, never at import time: only
 one process may load the TPU compiler library, and every test worker
 imports every test file."""
 import os
 
+import re
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
+from repro.config.base import SPDPlanConfig, replace
+from repro.configs import get_config
+from repro.core import model as M
 from repro.kernels import ops
 from repro.kernels import quant_collectives as QC
+from repro.parallel import tp as TP
+from repro.parallel.backend import ShardMapBackend
+from repro.runtime import forward as F
 
 D_MODEL, D_HEAD, PAGE = 2048, 128, 16
 HEADS = {"tp1": (16, 8), "tp4": (4, 2)}        # (query, kv) heads per chip
@@ -91,3 +102,54 @@ def test_dequant_accum_block_sync(one_chip):
                          ((n,), jnp.int8), ((n // 128,), jnp.float32),
                          ((n,), jnp.float32))
     assert "tpu_custom_call" in txt
+
+
+def test_paged_decode_updates_pools_in_place(topo):
+    """The served paged decode step (runtime/forward.paged_decode_step on
+    the shard backend) at qwen3-1.7b widths, cut to 4 layers, 512 pages
+    of 16, 8 slots and a 64-page table: the layer scan carries the
+    stacked page pools, so no op copies or dynamic-update-slices a whole
+    pool, no op slices out one layer's pool, and the step's temporaries
+    stay below one pool's bytes."""
+    from jax.sharding import Mesh
+    cfg = replace(get_config("qwen3-1.7b"), n_layers=4)
+    plan = SPDPlanConfig.none(cfg.n_layers)
+    slots, width, pages = 8, 64, 512
+    mesh = Mesh(np.asarray(topo.devices[:1]).reshape(1, 1),
+                ("data", "model"))
+    step = ShardMapBackend(cfg, plan, mesh).wrap(
+        *F.paged_decode_step(cfg, plan, tp=1))
+
+    def shapes(tree, pspecs):
+        return jax.tree.map(
+            lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+            tree, TP.named(mesh, pspecs))
+
+    params = shapes(jax.eval_shape(lambda: M.stack_segments(
+        M.pad_model(M.init_model(jax.random.PRNGKey(0), cfg), cfg, 1),
+        cfg, plan)), TP.param_pspecs(cfg, plan))
+    pools = shapes(M.paged_cache_struct(
+        cfg, plan, slots, width * PAGE, 1, page_size=PAGE, num_pages=pages),
+        TP.cache_pspecs(cfg, plan, mesh, shard_batch=False))
+    rep = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
+    compiled = step.lower(
+        params,
+        jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=rep),
+        jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=rep),
+        jax.ShapeDtypeStruct((slots, width), jnp.int32, sharding=rep),
+        pools).compile()
+    pool = pools[0]["k"]
+    assert pool.shape == (cfg.n_layers, pages + 1, PAGE, 8, D_HEAD)
+    shape = "bf16[" + ",".join(map(str, pool.shape)) + "]"
+    txt = compiled.as_text()
+    pool_sized = [ln.strip() for ln in txt.splitlines()
+                  if re.search(r"= " + re.escape(shape)
+                               + r"\S* (copy|dynamic-update-slice)\(", ln)]
+    assert not pool_sized, pool_sized
+    # nor is one layer's pool sliced out of the stack to be read
+    layer = r"= bf16\[(1,)?" + ",".join(map(str, pool.shape[1:])) + r"\]"
+    layer_sized = [ln.strip() for ln in txt.splitlines()
+                   if re.search(layer, ln)]
+    assert not layer_sized, layer_sized
+    pool_bytes = int(np.prod(pool.shape)) * pool.dtype.itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
