@@ -93,6 +93,7 @@ class Run:
     trace: Optional[dict] = None        # trace.reduce() of the traced part
     traced: Optional[tuple] = None      # (t0, t1) host times of that part
     allreduces_per_decode: Optional[int] = None
+    program_spans: Optional[list] = None  # spans.from_tracer(), traced run
 
     # -- helpers the readers share --
     def window_steps(self, lo=None, hi=None) -> List[Step]:
@@ -124,15 +125,32 @@ class Run:
             out.append(first - self._due(r))
         return out
 
-    def token_gaps(self) -> List[float]:
-        """Every gap between consecutive output tokens of a request whose
-        later token came in the window."""
-        out = []
+    def _window_gaps(self):
+        """(gap, time of the later token) for every gap between
+        consecutive output tokens of a request whose later token came in
+        the window; a token's time is the end of the step that made it."""
         for r in self.requests:
             tt = r.token_times
             for a, b in zip(tt, tt[1:]):
                 if self.w0 < b <= self.w1:
-                    out.append(b - a)
+                    yield b - a, b
+
+    def token_gaps(self) -> List[float]:
+        return [g for g, _ in self._window_gaps()]
+
+    def gap_summary(self) -> dict:
+        """The window's token gaps: their count, the share whose closing
+        step admitted a request (`Step.prefills` non-empty), and the
+        percentiles the gap metrics were chosen among, in ms."""
+        admitting = {s.t1 for s in self.steps if s.prefills}
+        pairs = list(self._window_gaps())
+        gaps = [g for g, _ in pairs]
+        held = sum(b in admitting for _, b in pairs)
+        out = {"n": len(gaps),
+               "admit_share": held / len(gaps) if gaps else None}
+        for q in (50, 90, 95, 97, 98, 99, 99.5):
+            v = percentile(gaps, q)
+            out[f"p{q:g}"] = None if v is None else 1e3 * v
         return out
 
     def window_tokens(self) -> int:

@@ -5,8 +5,10 @@ A TPU trace holds, per chip, a plane `/device:TPU:<i>` with a line
 "XLA Modules" (one event per program execution, named
 `<module>(<program id>)`) and a line "XLA Ops" (one event per operation,
 named by its HLO instruction text).  The host plane `/host:CPU` holds
-the benchmark's own `jax.profiler.TraceAnnotation` spans, all named
-`bench.*`.  Every step program of the server is a module named
+the benchmark's own `jax.profiler.TraceAnnotation` spans, named
+`bench.*`, and, where a `repro.obs.Recorder` with a profiler-mirroring
+tracer was attached, the program's spans (`scheduler.*`, `pool.*`;
+bench/lib/spans.py).  Every step program of the server is a module named
 `jit_local`, so a program is told apart by its id: `label_programs`
 maps ids to kinds from a short labelled session in which each program
 kind runs once inside a span `bench.label.<kind>`.
@@ -22,6 +24,8 @@ from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
 Interval = Tuple[str, float, float]          # (name, start_ns, end_ns)
+
+HOST_SPANS = ("bench.", "scheduler.", "pool.")
 
 _MODULE = re.compile(r"^(?P<name>.*)\((?P<pid>-?\d+)\)$")
 _OPCODE = re.compile(r"\s([a-z][a-z0-9_.\-]*)\(")
@@ -70,7 +74,7 @@ def load(path: str) -> Trace:
             for line in plane.lines:
                 tr.spans.extend(
                     (e.name, e.start_ns, e.start_ns + e.duration_ns)
-                    for e in line.events if e.name.startswith("bench."))
+                    for e in line.events if e.name.startswith(HOST_SPANS))
     tr.spans.sort(key=lambda s: s[1])
     return tr
 
@@ -137,16 +141,10 @@ def merged(evs: List[Interval]) -> List[Tuple[float, float]]:
     return [(s, e) for s, e in out]
 
 
-def _span_at(spans: List[Interval], s: float, e: float) -> str:
-    """The host span that covers most of [s, e], or 'host:other'."""
-    best, cover = "host:other", 0.0
-    for n, ss, se in spans:
-        if n.startswith("bench.label.") or n == "bench.traced":
-            continue
-        c = min(e, se) - max(s, ss)
-        if c > cover:
-            best, cover = n, c
-    return best
+def _framing(name: str) -> bool:
+    """The label and traced-window spans frame the trace: no gap is
+    named by them."""
+    return name.startswith("bench.label.") or name == "bench.traced"
 
 
 def reduce(tr: Trace, labels: Dict[str, str], lo: float, hi: float,
@@ -162,8 +160,11 @@ def reduce(tr: Trace, labels: Dict[str, str], lo: float, hi: float,
     device_ops         the `top` op names (kind:instruction) by seconds
                        on the first chip
     idle_gaps          the `top` longest gaps between ops on the first
-                       chip, each named by the host span it falls in
+                       chip, each named by the innermost host span that
+                       covers most of it (`spans.innermost`)
     """
+    from bench.lib.spans import innermost   # spans imports this module
+
     chips = tr.chips()
     out = {"window_s": (hi - lo) / 1e9, "busy_s": {}, "programs": {},
            "allreduce_s": {}, "device_ops": [], "idle_gaps": []}
@@ -203,6 +204,6 @@ def reduce(tr: Trace, labels: Dict[str, str], lo: float, hi: float,
             gaps.append((prev, s))
         prev = max(prev, e)
     gaps.sort(key=lambda g: g[0] - g[1])
-    out["idle_gaps"] = [[_span_at(tr.spans, s, e), (e - s) / 1e9]
-                        for s, e in gaps[:top]]
+    out["idle_gaps"] = [[innermost(tr.spans, s, e, skip=_framing),
+                         (e - s) / 1e9] for s, e in gaps[:top]]
     return out

@@ -18,7 +18,9 @@ use, counts); the last line is the result:
 
 With --trace 0 the metrics are the cell's end-to-end metrics; with
 --trace 1 its per-layer metrics, read from a profiler trace of part of
-the window and from the harness's records.  The numbers `correct` is
+the window, from the program's own spans over the whole window (a
+`repro.obs.Recorder` attached to the scheduler for the window alone) and
+from the harness's records.  The numbers `correct` is
 judged by end stderr and the result line, each beside its limit.
 
 Exits non-zero, printing no result, when JAX's first device is not a
@@ -110,11 +112,13 @@ def run_one(cell, args, devices, pk: dict, n_devices: int,
     from bench.lib import check as C
     from bench.lib import decoder as D
     from bench.lib import harness as H
+    from bench.lib import spans as SP
     from bench.lib import spec
     from bench.lib import trace as TR
     from bench.lib import traffic as T
     from repro.api import LLM
     from repro.config.base import ModelConfig
+    from repro.obs import MetricsRegistry, Recorder, Tracer
 
     d0 = devices[0]
     cache_dir = compile_cache()
@@ -157,9 +161,22 @@ def run_one(cell, args, devices, pk: dict, n_devices: int,
     run = H.Run(cell=cell, arch=arch, tp=load["tp"],
                 max_batch=load["max_batch"], num_pages=load["num_pages"],
                 seconds=args.seconds, peaks=pk, chips=len(devices))
-    H.serve_window(run, server.sched, reqs, mix,
-                   trace_dir=os.path.join(tmp, "window") if tmp else None,
-                   trace_s=float(mix.get("trace_s", 5.0)), counter=counter)
+    # a traced run records the program's own spans over the window only:
+    # warm-up and labelling ran with no recorder attached
+    rec = Recorder(MetricsRegistry(), Tracer(profiler=True)) \
+        if args.trace else None
+    server.sched.set_obs(rec)
+    try:
+        H.serve_window(run, server.sched, reqs, mix,
+                       trace_dir=os.path.join(tmp, "window") if tmp
+                       else None,
+                       trace_s=float(mix.get("trace_s", 5.0)),
+                       counter=counter)
+    finally:
+        server.sched.set_obs(None)
+    if rec:
+        run.program_spans = SP.from_tracer(
+            rec.tracer.to_dict()["traceEvents"], rec.tracer.origin)
     run.setup_s = run.w0 - T_START + AGE_AT_START
     mem_peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
                    for d in devices)
@@ -173,6 +190,7 @@ def run_one(cell, args, devices, pk: dict, n_devices: int,
           "late_max_s": max(run.lateness) if run.lateness else 0.0,
           "preemptions": server.sched.n_preemptions,
           "requests_sent": len(run.requests),
+          "token_gaps": run.gap_summary(),
           "memory_peak_bytes": mem_peak})
     server.free()
     del server

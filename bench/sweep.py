@@ -71,11 +71,13 @@ def main(argv=None):
         steps = run.window_steps()
         q = [s.queue for s in steps]
         tt = run.ttfts()
+        gaps = run.token_gaps()
         R.emit({"rate_per_s": rate,
                 "out_tok_s": run.window_tokens() / (run.w1 - run.w0),
                 "ttft_p50_ms": 1e3 * H.percentile(tt, 50),
                 "ttft_p90_ms": 1e3 * H.percentile(tt, 90),
-                "itl_p95_ms": 1e3 * H.percentile(run.token_gaps(), 95),
+                "itl_p50_ms": 1e3 * H.percentile(gaps, 50),
+                "itl_p99_ms": 1e3 * H.percentile(gaps, 99),
                 "queue_first_quarter": float(np.mean(q[:len(q) // 4])),
                 "queue_last_quarter": float(np.mean(q[-len(q) // 4:])),
                 "occupancy": float(np.mean([s.rows for s in steps]))

@@ -55,7 +55,7 @@ def runmod():
                             "bench_run_for_tests")
 
 
-def _run(runmod, monkeypatch, config, seed=7, control=False):
+def _run(runmod, monkeypatch, config, seed=7, control=False, trace=0):
     monkeypatch.setattr(runmod, "compile_cache", lambda: "off")
     bm = spec.benchmark()
     cell = spec.Cell(
@@ -65,7 +65,7 @@ def _run(runmod, monkeypatch, config, seed=7, control=False):
                "limits": {"max_logit_gap": LIMIT, "min_requests": 2}},
         end_to_end=bm["end_to_end"], per_layer=bm["per_layer"])
     args = argparse.Namespace(workload="small", seed=seed, seconds=1.5,
-                              trace=0)
+                              trace=trace)
     devs = jax.devices()[:cell.chips]
     return runmod.run_one(cell, args, devs, PEAKS, len(jax.devices()),
                           control=control)
@@ -90,8 +90,39 @@ def test_sound_run_is_correct(runmod, monkeypatch, config):
     assert out["correct"], out["check"]
     assert out["attempted"] > 0
     assert set(out["metrics"]) == {"ttft_p50_ms", "ttft_p90_ms",
-                                   "itl_p95_ms", "out_tok_s", "setup_s"}
+                                   "itl_p50_ms", "itl_p99_ms",
+                                   "out_tok_s", "setup_s"}
     assert list(out)[-1] == "check"
+
+
+def test_traced_run_reads_the_program_spans(runmod, monkeypatch, capsys):
+    """A --trace 1 run attaches the program's Recorder for the window
+    alone: the admission and host-phase readers find its spans there,
+    and the scheduler is left with no recorder attached."""
+    import json
+
+    from bench.lib import harness as H
+    from repro.obs import NULL_RECORDER
+
+    seen, free = [], H.Server.free
+
+    def spy(self):
+        seen.append(self.sched.obs)
+        free(self)
+
+    monkeypatch.setattr(H.Server, "free", spy)
+    out = _run(runmod, monkeypatch, QWEN, trace=1)
+    assert out["correct"], out["check"]
+    m = out["metrics"]
+    assert m["sched.admit_ms"]["value"] > 0
+    assert m["sched.host_ms"]["value"] > 0
+    assert seen == [NULL_RECORDER]
+    window = next(json.loads(ln) for ln in
+                  capsys.readouterr().out.splitlines()
+                  if '"phase": "window"' in ln)
+    gaps = window["token_gaps"]
+    assert gaps["n"] > 0 and 0 < gaps["admit_share"] < 1
+    assert gaps["p50"] <= gaps["p99"] <= gaps["p99.5"]
 
 
 def _token_altered(a, res):

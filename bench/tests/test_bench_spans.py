@@ -148,3 +148,23 @@ def test_attn_share_reader():
     assert read(SimpleNamespace(trace={"decode_attn_share": 48.5})) == 48.5
     assert read(SimpleNamespace(trace={"programs": {}})) is None
     assert read(SimpleNamespace(trace=None)) is None
+
+
+def test_cell_reads_the_span_metrics_from_the_programs_recorder(fx, spans):
+    """The cell's per-layer entries reach the span readers through
+    `Run.program_spans`, which a traced run fills from the Recorder's
+    tracer; with no device trace and no steps nothing else reads."""
+    from bench.lib import harness as H
+    from bench.lib import spec
+
+    cell = spec.cell("qwen3-1.7b.chat-open")
+    run = H.Run(cell=cell, arch=None, tp=1, max_batch=32, num_pages=64,
+                seconds=1.0, peaks={}, chips=1)
+    run.w0, run.w1 = fx["window"]
+    assert spec.read_metrics(cell.per_layer, run) == {}
+    run.program_spans = spans
+    got = spec.read_metrics(cell.per_layer, run)
+    assert got == {"sched.host_ms": {"value": pytest.approx(0.115),
+                                     "unit": "ms"},
+                   "sched.admit_ms": {"value": pytest.approx(0.34),
+                                      "unit": "ms"}}

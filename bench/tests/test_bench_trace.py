@@ -54,6 +54,22 @@ def test_idle_gaps_are_named_by_host_span(small):
     assert set(r["programs"]) == {"other"}
 
 
+def test_idle_gaps_take_the_innermost_program_span():
+    """With the program's spans on the host plane, a gap inside the
+    scheduler's host phase is named by it, not by the step around it;
+    the label and traced-window spans name nothing."""
+    tr = TR.Trace(
+        modules={"/device:TPU:0": [("jit_local(1)", 0, 1000),
+                                   ("jit_local(1)", 3000, 4000)]},
+        ops={"/device:TPU:0": [("%fusion.1 = f()", 0, 1000),
+                               ("%fusion.1 = f()", 3000, 4000)]},
+        spans=[("bench.traced", 0, 4000), ("bench.label.decode", 0, 4000),
+               ("bench.step", 900, 3100), ("scheduler.step", 950, 3050),
+               ("scheduler.prep", 1000, 2900), ("pool.table", 2000, 2100)])
+    assert TR.reduce(tr, {}, 0, 4000)["idle_gaps"] == [
+        ["scheduler.prep", pytest.approx(2e-6)]]
+
+
 def test_window_clips_events(small):
     r = TR.reduce(small, {"111": "decode"}, 2000, 13000)
     assert r["busy_s"]["/device:TPU:0"] == pytest.approx(7e-6)
@@ -87,3 +103,25 @@ def test_reads_host_spans_from_a_recorded_trace(tmp_path):
     names = [s[0] for s in tr.spans]
     assert names == ["bench.step", "bench.wait"]
     assert all(e > s for _, s, e in tr.spans)
+
+
+def test_load_keeps_the_programs_spans(tmp_path):
+    """The Recorder's profiler mirror (`Tracer(profiler=True)`) puts the
+    scheduler's and the pool's spans on the host plane; `load` keeps
+    them beside the benchmark's, and nothing else of that plane."""
+    import jax
+
+    from repro.obs import MetricsRegistry, Recorder, Tracer
+
+    rec = Recorder(MetricsRegistry(), Tracer(profiler=True))
+    jax.profiler.start_trace(str(tmp_path))
+    with jax.profiler.TraceAnnotation("bench.step"):
+        with rec.span("scheduler", "prep"):
+            with rec.span("pool", "cow"):
+                time.sleep(0.001)
+    with jax.profiler.TraceAnnotation("other.thing"):
+        time.sleep(0.001)
+    jax.profiler.stop_trace()
+    tr = TR.load(TR.find_xplane(str(tmp_path)))
+    assert [s[0] for s in tr.spans] == ["bench.step", "scheduler.prep",
+                                        "pool.cow"]
